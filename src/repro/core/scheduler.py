@@ -120,6 +120,10 @@ class PeriodicAsyncScheduler:
         self._inflight: List = []
         self._key = None
         self._train_busy = 0.0
+        # per-iteration device scalars: micro-step losses and the update's
+        # pre-clip gradient norm, read once when the iteration's stats land
+        self._losses: List = []
+        self._grad_norm = None
         # registry metrics for the live ops plane (/metrics): cached
         # handles, pushed once per iteration at the boundary
         _m = metrics()
@@ -190,8 +194,9 @@ class PeriodicAsyncScheduler:
                 step = self.grad_step
             with otrace.span("train.grad_step",
                              captured=mb.logp_behavior is not None):
-                grads, metrics = step(self.tri.policy, self.tri.old,
-                                      self.tri.ref, mb)
+                grads, step_metrics = step(self.tri.policy, self.tri.old,
+                                           self.tri.ref, mb)
+                self._losses.append(step_metrics["loss"])
                 # repro: allow(host-sync): trainer-side busy-time measurement
                 # barrier (paper Table 7 timing); not a decode path
                 jax.block_until_ready(jax.tree.leaves(grads)[0])
@@ -206,8 +211,11 @@ class PeriodicAsyncScheduler:
 
     def _finish_iteration(self, acc: GradAccumulator) -> None:
         t0 = time.perf_counter()
-        new_params, new_opt, _ = self.apply_update(
-            self.tri.policy, self.tri.opt, acc.mean())
+        grads = acc.mean()
+        acc.reset()          # free the f32 sum before the update allocates
+        new_params, new_opt, opt_metrics = self.apply_update(
+            self.tri.policy, self.tri.opt, grads)
+        self._grad_norm = opt_metrics["grad_norm"]
         # repro: allow(host-sync): update must materialise before the
         # version flip (Proposition 1 boundary); trainer-side, once per
         # iteration
@@ -319,6 +327,7 @@ class PeriodicAsyncScheduler:
                 busy0 = pool.busy_time
                 engine0 = pool.engine_stats()
                 self._train_busy = 0.0
+                self._losses = []
                 acc = GradAccumulator()
                 rewards_seen: List[float] = []
                 trained_tokens = 0
@@ -403,7 +412,12 @@ class PeriodicAsyncScheduler:
                     metrics={"sync_gap": self.transfer.last_gap,
                              "spec_acceptance": spec_acceptance,
                              "prefix_hit_rate": prefix_hit_rate,
-                             "pages_reclaimed": d["reclaimed_pages"]})
+                             "pages_reclaimed": d["reclaimed_pages"],
+                             # repro: allow(host-sync): once per iteration,
+                             # after the update has materialised
+                             "loss": float(np.mean(jax.device_get(
+                                 self._losses))),
+                             "grad_norm": float(self._grad_norm)})
                 self.history.append(stats)
                 self._m_iteration.set(start + t + 1)
                 self._m_trained_tokens.add(trained_tokens)

@@ -12,10 +12,11 @@ Two query shapes share one kernel body:
     token attend in ONE pass, each query row masked by its OWN position, so
     intra-block causality needs no extra machinery.
 
-Layout: grid = (B, Hkv, nL) with the cache-length axis innermost; queries
-are flattened to R = q_len * G rows per Hkv head and the (R, Dv)
-accumulator lives in VMEM scratch. Invalid cache slots carry pos >= 2**30
-and are masked by the causal rule.
+Layout: grid = (B, Hkv, nR, nL) with the cache-length axis innermost;
+queries are flattened to R = q_len * G rows per Hkv head and blocked by up
+to ``ROW_BLOCK`` rows, K/V are read head-major in (bL, D) blocks, and each
+row block's (bR, Dv) accumulator lives in VMEM scratch.
+Invalid cache slots carry pos >= 2**30 and are masked by the causal rule.
 """
 from __future__ import annotations
 
@@ -28,13 +29,17 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 NEG_INF = -1e30
+# query rows per block: a verify block or a paged suffix prefill flattens
+# q_len * G rows, which must not all sit in VMEM at once (256 rows of
+# MLA's 576-wide queries plus their f32 accumulator fit its 16 MiB budget)
+ROW_BLOCK = 256
 
 
 def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
             o_ref,
             acc_ref, m_ref, l_ref,
             *, scale: float, window: Optional[int], nL: int):
-    li = pl.program_id(2)
+    li = pl.program_id(3)
 
     @pl.when(li == 0)
     def _init():
@@ -42,15 +47,15 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (R, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (bL, D)
+    q = q_ref[0, 0].astype(jnp.float32)            # (bR, D)
+    k = k_ref[0, 0].astype(jnp.float32)            # (bL, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale  # (R, bL)
-    qp = qpos_ref[0]                               # (R,) per-query positions
-    kp = kpos_ref[0]                               # (bL,)
-    ok = kp[None, :] <= qp[:, None]
+                            preferred_element_type=jnp.float32) * scale  # (bR, bL)
+    qp = qpos_ref[0]                               # (bR, 1) per-row positions
+    kp = kpos_ref[0]                               # (1, bL)
+    ok = kp <= qp
     if window is not None:
-        ok &= (qp[:, None] - kp[None, :]) < window
+        ok &= (qp - kp) < window
     s = jnp.where(ok, s, NEG_INF)
 
     m_prev = m_ref[...]
@@ -58,7 +63,7 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    v = v_ref[0, :, 0].astype(jnp.float32)         # (bL, Dv)
+    v = v_ref[0, 0].astype(jnp.float32)            # (bL, Dv)
     pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
     acc_ref[...] = acc_ref[...] * corr + pv
@@ -78,9 +83,21 @@ def _flash_rows(qr, k, v, kv_pos, q_pos_rows, *, scale: float,
     own position (decode broadcasts one position over G rows; verify
     interleaves q_len positions x G). k/v: (B, L, Hkv, Dv); kv_pos: (B, L).
     Returns (B, Hkv, R, Dv) in qr.dtype.
+
+    Every block's last two dims are (8, 128)-divisible or span the array
+    (Mosaic's tiling rule): K/V go to the kernel head-major as (B, Hkv, L,
+    D) blocks of (bL, D), query positions as a (B, R, 1) column and cache
+    positions as a (B, 1, L) row. Rows are blocked by ``ROW_BLOCK``.
     """
     B, Hkv, R, D = qr.shape
     _, L, _, Dv = v.shape
+
+    bR = R if R <= ROW_BLOCK else ROW_BLOCK
+    pad_r = (-R) % bR
+    if pad_r:       # padded rows attend at position 0 and are cut below
+        qr = jnp.pad(qr, ((0, 0), (0, 0), (0, pad_r), (0, 0)))
+        q_pos_rows = jnp.pad(q_pos_rows, ((0, 0), (0, pad_r)))
+    nR = (R + pad_r) // bR
 
     bL = min(block_l, L)
     pad = (-L) % bL
@@ -90,28 +107,32 @@ def _flash_rows(qr, k, v, kv_pos, q_pos_rows, *, scale: float,
         kv_pos = jnp.pad(kv_pos, ((0, 0), (0, pad)), constant_values=2**30)
     L_p = L + pad
     nL = L_p // bL
+    k = jnp.swapaxes(k, 1, 2)                      # (B, Hkv, L_p, D)
+    v = jnp.swapaxes(v, 1, 2)
 
-    grid = (B, Hkv, nL)
+    grid = (B, Hkv, nR, nL)
     kernel = functools.partial(_kernel, scale=scale, window=window, nL=nL)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, R), lambda b, h, li: (b, 0)),           # q_pos
-            pl.BlockSpec((1, 1, R, D), lambda b, h, li: (b, h, 0, 0)),
-            pl.BlockSpec((1, bL, 1, D), lambda b, h, li: (b, li, h, 0)),
-            pl.BlockSpec((1, bL, 1, Dv), lambda b, h, li: (b, li, h, 0)),
-            pl.BlockSpec((1, bL), lambda b, h, li: (b, li)),         # kv_pos
+            pl.BlockSpec((1, bR, 1), lambda b, h, r, li: (b, r, 0)),  # q_pos
+            pl.BlockSpec((1, 1, bR, D), lambda b, h, r, li: (b, h, r, 0)),
+            pl.BlockSpec((1, 1, bL, D), lambda b, h, r, li: (b, h, li, 0)),
+            pl.BlockSpec((1, 1, bL, Dv), lambda b, h, r, li: (b, h, li, 0)),
+            pl.BlockSpec((1, 1, bL), lambda b, h, r, li: (b, 0, li)),  # kv_pos
         ],
-        out_specs=pl.BlockSpec((1, 1, R, Dv), lambda b, h, li: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, Dv), qr.dtype),
+        out_specs=pl.BlockSpec((1, 1, bR, Dv),
+                               lambda b, h, r, li: (b, h, r, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, R + pad_r, Dv), qr.dtype),
         scratch_shapes=[
-            pltpu.VMEM((R, Dv), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((bR, Dv), jnp.float32),
+            pltpu.VMEM((bR, 1), jnp.float32),
+            pltpu.VMEM((bR, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_pos_rows, qr, k, v, kv_pos)
+    )(q_pos_rows[:, :, None], qr, k, v, kv_pos[:, None, :])
+    return out[:, :, :R] if pad_r else out
 
 
 @functools.partial(

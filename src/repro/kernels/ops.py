@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode; on TPU the
-same calls compile to Mosaic. ``auto_interpret()`` picks per backend.
+On the TPU the calls compile to Mosaic (the chip path); elsewhere they run
+in Pallas interpret mode, the CPU test path. ``auto_interpret()`` picks per
+backend.
 """
 from __future__ import annotations
 

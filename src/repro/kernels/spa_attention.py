@@ -26,12 +26,14 @@ import jax.experimental.pallas.tpu as pltpu
 NEG_INF = -1e30
 
 
-def _kernel(bmap_ref, qpos_ref, kpos_ref, qseg_ref, kseg_ref,
+def _kernel(bmap_ref,                       # scalar prefetch (SMEM)
+            qpos_ref, kpos_ref, qseg_ref, kseg_ref,
             q_ref, k_ref, v_ref,            # inputs
             o_ref,                          # output
             acc_ref, m_ref, l_ref,          # VMEM scratch
-            *, scale: float, window: Optional[int], nk: int):
-    ki = pl.program_id(2)
+            *, scale: float, window: Optional[int], heads: int, nq: int,
+            nk: int):
+    bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -39,17 +41,17 @@ def _kernel(bmap_ref, qpos_ref, kpos_ref, qseg_ref, kseg_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(bmap_ref[0, 0, 0] != 0)
+    @pl.when(bmap_ref[((bh // heads) * nq + qi) * nk + ki] != 0)
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # (bq, D)
         k = k_ref[0].astype(jnp.float32)            # (bk, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # (bq, bk)
-        qp = qpos_ref[0][:, None]                   # (bq, 1)
-        kp = kpos_ref[0][None, :]                   # (1, bk)
-        qs = qseg_ref[0][:, None]
-        ks = kseg_ref[0][None, :]
+        qp = qpos_ref[0]                            # (bq, 1)
+        kp = kpos_ref[0]                            # (1, bk)
+        qs = qseg_ref[0]
+        ks = kseg_ref[0]
         allow = (kp <= qp) & ((ks == 0) | (ks == qs))
         if window is not None:
             allow &= (qp - kp) < window
@@ -142,29 +144,41 @@ def spa_attention(q, k, v, q_pos, kv_pos, q_seg, kv_seg, *,
     vf = jnp.moveaxis(v, 2, 1).reshape(B * H, Skv_p, Dv)
 
     grid = (B * H, nq, nk)
-    kernel = functools.partial(_kernel, scale=scale, window=window, nk=nk)
-    out = pl.pallas_call(
-        kernel,
+    kernel = functools.partial(_kernel, scale=scale, window=window, heads=H,
+                               nq=nq, nk=nk)
+    # Every block's last two dims are (8, 128)-divisible or span the array
+    # (Mosaic's tiling rule): query positions/segments go in as (B, Sq, 1)
+    # columns, kv ones as (B, 1, Skv) rows, and the tile map rides scalar
+    # prefetch (SMEM), flattened.
+    col = lambda a: a[:, :, None]
+    row = lambda a: a[:, None, :]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, 1), lambda b, qi, ki: (b // H, qi, ki)),
-            pl.BlockSpec((1, bq), lambda b, qi, ki: (b // H, qi)),
-            pl.BlockSpec((1, bk), lambda b, qi, ki: (b // H, ki)),
-            pl.BlockSpec((1, bq), lambda b, qi, ki: (b // H, qi)),
-            pl.BlockSpec((1, bk), lambda b, qi, ki: (b // H, ki)),
-            pl.BlockSpec((1, bq, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, qi, ki, bm: (b // H, qi, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, qi, ki, bm: (b // H, 0, ki)),
+            pl.BlockSpec((1, bq, 1), lambda b, qi, ki, bm: (b // H, qi, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, qi, ki, bm: (b // H, 0, ki)),
+            pl.BlockSpec((1, bq, D), lambda b, qi, ki, bm: (b, qi, 0)),
+            pl.BlockSpec((1, bk, D), lambda b, qi, ki, bm: (b, ki, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda b, qi, ki, bm: (b, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, Dv), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, Dv),
+                               lambda b, qi, ki, bm: (b, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, Dv), jnp.float32),   # acc
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # running sum
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, Dv), q.dtype),
         interpret=interpret,
-    )(bmap, q_pos, kv_pos, q_seg, kv_seg, qf, kf, vf)
+    )(bmap.reshape(-1), col(q_pos), row(kv_pos), col(q_seg), row(kv_seg),
+      qf, kf, vf)
 
     out = out.reshape(B, H, Sq_p, Dv)[:, :, :Sq]
     return jnp.moveaxis(out, 1, 2)

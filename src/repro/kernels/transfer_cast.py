@@ -19,8 +19,8 @@ are a rounding error of the tree's bytes. Rounding is XLA's convert
 ``x.astype(dtype)`` — asserted in tests/test_transfer.py against the
 pure-JAX path.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU the same
-call compiles to Mosaic.
+On the chip the call compiles to Mosaic; interpret mode is the CPU test
+path (``kernels/ops.py`` picks per backend).
 """
 from __future__ import annotations
 
@@ -53,7 +53,7 @@ def _cast_call(x2d, dtype, bm: int, interpret: bool):
 
 @partial(jax.jit, static_argnames=("dtype", "block_rows", "interpret"))
 def transfer_cast(x, dtype, *, block_rows: int = 256,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """Fused cast+copy of one pytree leaf: ``x`` -> ``dtype``.
 
     Any shape/dtype in; value-equal to ``x.astype(dtype)`` out. No-op

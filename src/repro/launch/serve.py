@@ -36,6 +36,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config, reduced_config
 from repro.data.tasks import ArithmeticTask
 from repro.data.tokenizer import Tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init
 from repro.obs import trace as otrace
 from repro.rl.rollout import Sampler
@@ -425,6 +426,7 @@ def main(argv: Optional[list] = None) -> None:
                          "/healthz + /status introspect the engine "
                          "(obs/server.py; ctrl-C to stop)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if not (args.trace or args.trace_dir):
         _cli_run(args)
         return

@@ -28,6 +28,7 @@ from repro.core import (InferenceInstance, InferencePool, PeriodicAsyncScheduler
 from repro.data.loader import PromptLoader
 from repro.data.tasks import ArithmeticTask
 from repro.data.tokenizer import Tokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init
 from repro.obs import trace as otrace
 from repro.rl.reward import RuleBasedReward
@@ -214,6 +215,7 @@ def main(argv=None) -> None:
                          "(0 = ephemeral; the ops plane, DESIGN.md "
                          "§Observability)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:
@@ -294,6 +296,7 @@ def main(argv=None) -> None:
             extra += f" reclaimed={m['pages_reclaimed']}"
         print(f"  iter {s.iteration}: wall={s.wall_time:.2f}s "
               f"tokens={s.trained_tokens} reward={s.reward_mean:.3f} "
+              f"loss={m['loss']:.4g} grad_norm={m['grad_norm']:.4g} "
               f"staleness={s.max_staleness}{extra}")
     if args.json_out:
         with open(args.json_out, "w") as f:

@@ -8,8 +8,18 @@ makes completion-order consumption gradient-equivalent (Remark 1);
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _accumulate(total, grads, weight):
+    # the running sum is donated: the new sum reuses its buffers instead of
+    # holding two f32 copies of the model at once
+    return jax.tree.map(lambda a, g: a + g.astype(jnp.float32) * weight,
+                        total, grads)
 
 
 class GradAccumulator:
@@ -27,9 +37,7 @@ class GradAccumulator:
             self._sum = jax.tree.map(
                 lambda g: g.astype(jnp.float32) * weight, grads)
         else:
-            self._sum = jax.tree.map(
-                lambda a, g: a + g.astype(jnp.float32) * weight,
-                self._sum, grads)
+            self._sum = _accumulate(self._sum, grads, jnp.float32(weight))
         self._weight += float(weight)
         self._count += 1
 

@@ -149,7 +149,11 @@ def make_grad_step_captured(cfg: ModelConfig, rl: RLConfig):
 
 
 def make_apply_update(cfg: ModelConfig, rl: RLConfig):
-    @jax.jit
+    # the optimizer state and the averaged gradients are dead after the
+    # update: donating them lets the new moments reuse their HBM, which a
+    # published-width model on one chip needs (the policy is not donated —
+    # the old-policy slot still aliases it)
+    @partial(jax.jit, donate_argnums=(1, 2))
     def apply_update(policy_params, opt_state, grads):
         return adam_update(policy_params, grads, opt_state,
                            lr=rl.learning_rate, b1=rl.adam_b1, b2=rl.adam_b2,

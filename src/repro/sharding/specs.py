@@ -183,17 +183,11 @@ def spec_for(mesh: Mesh, shape: tuple, logical: tuple) -> P:
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable fully-manual shard_map with replication checking
-    off (the callers' out_specs deliberately leave collectively-reduced /
-    replicated axes unmentioned): newer JAX exposes ``jax.shard_map``
-    with ``check_vma``, older releases only
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """Fully-manual ``jax.shard_map`` with replication checking off (the
+    callers' out_specs deliberately leave collectively-reduced /
+    replicated axes unmentioned)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def constrain(x: jax.Array, *logical):

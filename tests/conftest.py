@@ -11,7 +11,7 @@ jax.config.update("jax_enable_x64", False)
 
 
 # --------------------------------------------------------------------------
-# hypothesis (optional dependency — the container has no wheel baked in)
+# hypothesis (optional dependency)
 # --------------------------------------------------------------------------
 
 def require_hypothesis():

@@ -9,9 +9,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ref import decode_attention_ref, spa_attention_ref
+from repro.kernels.ref import (decode_attention_ref, spa_attention_ref,
+                               verify_attention_ref)
 from repro.kernels.spa_attention import block_map, spa_attention
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import (ROW_BLOCK, decode_attention,
+                                            verify_attention)
 
 INTERP = dict(interpret=True)
 
@@ -202,4 +204,23 @@ def test_decode_kernel_matches_spa_kernel_single_token():
     b = decode_attention(q[:, 0], k, v, kv_pos, q_pos1[:, 0],
                          block_l=32, **INTERP)
     np.testing.assert_allclose(np.asarray(a[:, 0]), np.asarray(b),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("S", [128, 70])
+def test_verify_kernel_blocks_long_query_rows(S):
+    """A suffix-prefill-sized block (S * G query rows beyond ROW_BLOCK, a
+    ragged last row block when S = 70) matches the dense oracle."""
+    B, L, H, Hkv, D = 2, 160, 8, 2, 32
+    assert S * (H // Hkv) > ROW_BLOCK
+    key = jax.random.PRNGKey(11)
+    kq, kk, kv = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (B, S, H, D), jnp.float32)
+    k = jax.random.normal(kk, (B, L, Hkv, D), jnp.float32)
+    v = jax.random.normal(kv, (B, L, Hkv, D), jnp.float32)
+    kv_pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+    q_pos = jnp.broadcast_to(jnp.arange(L - S, L, dtype=jnp.int32), (B, S))
+    got = verify_attention(q, k, v, kv_pos, q_pos, block_l=32, **INTERP)
+    want = verify_attention_ref(q, k, v, kv_pos, q_pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)

@@ -71,6 +71,9 @@ def recorders(monkeypatch):
     }
     for name, rec in recs.items():
         monkeypatch.setattr(serve_mod, name, rec)
+    # main() turns on the persistent compile cache for the process; the
+    # test process keeps JAX's defaults
+    monkeypatch.setattr(serve_mod, "enable_compile_cache", lambda: None)
     return recs
 
 
