@@ -191,7 +191,9 @@ class InferenceInstance:
                               min_version: Optional[int] = None) -> tuple:
         """Token-level path: submit the group, then help drive the shared
         engine until it completes. Concurrent callers' groups share decode
-        steps — the engine lock serialises single steps, not whole groups."""
+        steps — the engine lock serialises single steps, not whole groups.
+        Each step is a ``producer.step`` span and the wait for the lock
+        before it a ``producer.lock_wait`` span, on the driving thread."""
         eng = self.paged_engine
         assert len(prompts) == eng.G, \
             f"group size {len(prompts)} != engine group_size {eng.G}"
@@ -209,11 +211,17 @@ class InferenceInstance:
         busy = 0.0
         t1 = 0.0
         while not handle.done():
+            # entered by hand so that it closes as the lock is taken,
+            # inside the `with` that holds it across the step
+            waiting = otrace.span("producer.lock_wait")
+            waiting.__enter__()
             with self._lock:
+                waiting.__exit__(None, None, None)
                 if handle.done():
                     break
                 t0 = time.perf_counter()
-                eng.step()
+                with otrace.span("producer.step"):
+                    eng.step()
                 t1 = time.perf_counter()
                 if drive0 is None:
                     drive0 = t0
@@ -275,7 +283,9 @@ class InferencePool:
         engine). Zeros when no instance runs a paged engine, so callers
         can diff snapshots unconditionally."""
         agg = {"decode_steps": 0, "generated_tokens": 0,
-               "reclaimed_pages": 0, "spec_steps": 0, "drafted_tokens": 0,
+               "reclaimed_pages": 0, "decode_host_s": 0.0,
+               "decode_launch_s": 0.0, "decode_wait_s": 0.0,
+               "decode_gap_s": 0.0, "spec_steps": 0, "drafted_tokens": 0,
                "accepted_tokens": 0, "prefix_hit_pages": 0,
                "prefix_miss_pages": 0, "prefix_evicted_pages": 0}
         for inst in self.instances:

@@ -301,6 +301,7 @@ class PagedGroupEngine:
         self.decode_steps = 0
         self.generated_tokens = 0
         self.reclaimed_pages = 0
+        self._reset_phase_clocks()
 
         self._pending: Optional[_Block] = None   # in-flight fused block
         self._done = None            # (B,) bool device-resident stop flags
@@ -323,6 +324,18 @@ class PagedGroupEngine:
         self._m_prefix_miss = _m.counter("prefix.miss_pages")
         self._m_prefix_evicted = _m.counter("prefix.evicted_pages")
         self._pushed_reclaimed = 0   # registry high-water for the counter
+
+    def _reset_phase_clocks(self) -> None:
+        """Host seconds of the decode step's phases (``step``): admission,
+        the block's build and commit (host), the device calls (launch), the
+        readback (wait), and the time between steps while rows are held
+        (gap). Prefill is in none of them."""
+        with self._mutex:
+            self.decode_host_s = 0.0
+            self.decode_launch_s = 0.0
+            self.decode_wait_s = 0.0
+            self.decode_gap_s = 0.0
+            self._step_end: Optional[float] = None
 
     def reset_spec_stats(self) -> None:
         with self._mutex:   # counters race with step() from other threads
@@ -359,6 +372,10 @@ class PagedGroupEngine:
                 "decode_steps": self.decode_steps,
                 "generated_tokens": self.generated_tokens,
                 "reclaimed_pages": self.reclaimed_pages,
+                "decode_host_s": self.decode_host_s,
+                "decode_launch_s": self.decode_launch_s,
+                "decode_wait_s": self.decode_wait_s,
+                "decode_gap_s": self.decode_gap_s,
                 "spec_steps": self.spec_steps,
                 "drafted_tokens": self.drafted_tokens,
                 "accepted_tokens": self.accepted_tokens,
@@ -663,6 +680,7 @@ class PagedGroupEngine:
             self.decode_steps = 0
             self.generated_tokens = 0
             self.reclaimed_pages = 0
+            self._reset_phase_clocks()
             # registry high-water follows the local counter it diffs
             # against — left stale it would push a NEGATIVE delta into
             # the monotone registry counter on the next drain
@@ -733,8 +751,11 @@ class PagedGroupEngine:
             jnp.asarray(segs), jnp.asarray(wsl[None]), jnp.asarray(tab),
             jnp.asarray([len(sfx) - 1], jnp.int32))
 
-    def _admit_row(self, slot: int, row: _Row) -> None:
+    def _admit_row(self, slot: int, row: _Row) -> float:
+        """Seat ``row`` in ``slot``; returns the host seconds spent
+        dispatching its group's prefill (0 for the group's later rows)."""
         g = row.group
+        prefill_s = 0.0
         if g.prompt_pages is None:
             j0, n_pp = self._prompt_page_range(len(g.prompt))
             m, mpages = g.match if g.match is not None else (j0, [])
@@ -762,6 +783,7 @@ class PagedGroupEngine:
                     self._m_prefix_miss.add(n_pp - j0)
             g.prompt_last = [min((j + 1) * self.page, len(g.prompt)) - 1
                              for j in range(j0, n_pp)]
+            t_prefill = time.perf_counter()
             if m > j0:
                 # span measures host-side dispatch (the prefill itself is
                 # asynchronous; its device time surfaces at the next drain)
@@ -780,6 +802,7 @@ class PagedGroupEngine:
                         self.params, self.caches, jnp.asarray(row_arr),
                         jnp.asarray([len(g.prompt)], jnp.int32),
                         jnp.asarray(dest))
+            prefill_s = time.perf_counter() - t_prefill
             if self.radix is not None:
                 # cache every COMPLETE prompt page (cold and warm alike —
                 # insert skips spans already cached); a trailing partial
@@ -807,6 +830,7 @@ class PagedGroupEngine:
         row.sched_t = 0
         if self.spec_k:
             self._draft.start(slot, g.prompt)
+        return prefill_s
 
     def _alloc_resp_page(self, slot: int, row: _Row, k: int) -> int:
         """Lazily take response page k (the write cursor just crossed a
@@ -922,27 +946,50 @@ class PagedGroupEngine:
         optimistic dispatch assumes no row stopped inside the in-flight
         block; the device-resident ``done`` flags make that exact (a
         stopped row's extra steps are masked to the trash page), and the
-        drain simply skips plan entries whose slot was re-assigned."""
+        drain simply skips plan entries whose slot was re-assigned.
+
+        Each phase is a span (``paged.admit``, then ``paged.build``/
+        ``paged.launch`` in ``_dispatch_block`` and ``paged.wait``/
+        ``paged.commit`` in ``_drain_block``) and feeds the always-on
+        phase clocks that ``stats_snapshot`` reads (``decode_host_s``,
+        ``decode_launch_s``, ``decode_wait_s``); the time from one step's
+        return to the next step's start is ``decode_gap_s`` while the
+        engine still holds rows, so an idle engine between iterations
+        counts nothing."""
         with self._mutex:
-            # admit one row at a time: _admit_row consumes pages, and the
-            # gate must see the freelist as it actually is for the NEXT row
-            while True:
-                admitted = self.sched.admit(self._admission_gate, limit=1)
-                if not admitted:
-                    break
-                self._admit_row(*admitted[0])
+            t0 = time.perf_counter()
+            if self._step_end is not None:
+                self.decode_gap_s += t0 - self._step_end
+            prefill_s = 0.0
+            with otrace.span("paged.admit"):
+                # admit one row at a time: _admit_row consumes pages, and
+                # the gate must see the freelist as it actually is for the
+                # NEXT row
+                while True:
+                    admitted = self.sched.admit(self._admission_gate,
+                                                limit=1)
+                    if not admitted:
+                        break
+                    prefill_s += self._admit_row(*admitted[0])
+            self.decode_host_s += time.perf_counter() - t0 - prefill_s
             act = self.sched.active_slots()
             if self.spec_k:
-                return self._spec_step(act) if act else False
-            nxt = self._dispatch_block(act) if act else None
-            if self.drain == 1:
+                ran = self._spec_step(act) if act else False
+            elif self.drain == 1:
+                nxt = self._dispatch_block(act) if act else None
                 if nxt is not None:
                     self._drain_block(nxt)
-                return nxt is not None
-            prev, self._pending = self._pending, nxt
-            if prev is not None:
-                self._drain_block(prev)
-            return nxt is not None or prev is not None
+                ran = nxt is not None
+            else:
+                nxt = self._dispatch_block(act) if act else None
+                prev, self._pending = self._pending, nxt
+                if prev is not None:
+                    self._drain_block(prev)
+                ran = nxt is not None or prev is not None
+            self._step_end = (None if self.sched.idle
+                              and self._pending is None
+                              else time.perf_counter())
+            return ran
 
     def _dispatch_block(self, act: List[int]) -> Optional[_Block]:
         """Build one fused decode block for the active slots and dispatch
@@ -954,100 +1001,104 @@ class PagedGroupEngine:
         receives the per-step keys/write-slots/valid masks as (D, B)
         arrays and runs free."""
         B, D, page = self.B, self.drain, self.page
-        t_disp = time.perf_counter()
-        keys = np.zeros((D, B, 2), np.uint32)
-        wsl = np.full((D, B), TRASH_PAGE * page, np.int32)
-        valid = np.zeros((D, B), bool)
-        rows = np.zeros((B,), np.int32)
-        pos0 = np.full((B,), INVALID_POS, np.int32)
-        active = np.zeros((B,), bool)
-        # fixed worst-case shape: each slot crosses at most D//page + 1
-        # page boundaries per block (trash-padding keeps the jit cache at
-        # one trace)
-        fresh = np.full((B * (D // page + 2),), TRASH_PAGE, np.int32)
-        n_fresh = 0
-        plan = []
-        for s in act:
-            row = self.sched.slot_req[s]
-            g = row.group
-            t0 = row.sched_t
-            if t0 >= g.max_new:      # fully scheduled; awaiting drain
-                continue
-            q0 = len(g.prompt) + t0
-            if self.window is not None:
-                self._reclaim_row(s, row, q0)
-            n_row = min(D, g.max_new - t0)
-            for t in range(t0, t0 + n_row):
-                k = t // page
-                if k == len(row.pages):       # crossed a page boundary
-                    fresh[n_fresh] = self._alloc_resp_page(s, row, k)
-                    n_fresh += 1
-                keys[t - t0, s] = g.keys[t]
-                wsl[t - t0, s] = row.pages[k] * page + t % page
-                valid[t - t0, s] = True
-            rows[s] = row.idx
-            pos0[s] = q0
-            active[s] = True
-            row.sched_t = t0 + n_row
-            plan.append((s, row, t0, n_row))
+        t_build = time.perf_counter()
+        with otrace.span("paged.build"):
+            keys = np.zeros((D, B, 2), np.uint32)
+            wsl = np.full((D, B), TRASH_PAGE * page, np.int32)
+            valid = np.zeros((D, B), bool)
+            rows = np.zeros((B,), np.int32)
+            pos0 = np.full((B,), INVALID_POS, np.int32)
+            active = np.zeros((B,), bool)
+            # fixed worst-case shape: each slot crosses at most D//page + 1
+            # page boundaries per block (trash-padding keeps the jit cache
+            # at one trace)
+            fresh = np.full((B * (D // page + 2),), TRASH_PAGE, np.int32)
+            n_fresh = 0
+            plan = []
+            for s in act:
+                row = self.sched.slot_req[s]
+                g = row.group
+                t0 = row.sched_t
+                if t0 >= g.max_new:      # fully scheduled; awaiting drain
+                    continue
+                q0 = len(g.prompt) + t0
+                if self.window is not None:
+                    self._reclaim_row(s, row, q0)
+                n_row = min(D, g.max_new - t0)
+                for t in range(t0, t0 + n_row):
+                    k = t // page
+                    if k == len(row.pages):       # crossed a page boundary
+                        fresh[n_fresh] = self._alloc_resp_page(s, row, k)
+                        n_fresh += 1
+                    keys[t - t0, s] = g.keys[t]
+                    wsl[t - t0, s] = row.pages[k] * page + t % page
+                    valid[t - t0, s] = True
+                rows[s] = row.idx
+                pos0[s] = q0
+                active[s] = True
+                row.sched_t = t0 + n_row
+                plan.append((s, row, t0, n_row))
+        t_launch = time.perf_counter()
+        self.decode_host_s += t_launch - t_build
         if not plan:
             return None
-        if n_fresh:
-            # one fixed-shape invalidation for every page freshly
-            # allocated this block — stale (pos, kv) from a previous
-            # occupant would otherwise pass the causal mask
-            self.caches = self._invalidate(self.caches, jnp.asarray(fresh))
-        base = self.sched.step
-        self.sched.step += D
-        toks, lps, self.caches, self.logits, self._done = self._decode(
-            self.params, self.caches, self.logits, self._done,
-            jnp.asarray(keys), jnp.asarray(wsl), jnp.asarray(valid),
-            jnp.asarray(rows), jnp.asarray(pos0), jnp.asarray(active),
-            jnp.asarray(self._ptab))
-        self.decode_steps += D
-        # start the device->host transfer NOW so it overlaps the next
-        # block's build + compute; the drain then finds it landed
-        for buf in (toks, lps):
-            if hasattr(buf, "copy_to_host_async"):
-                buf.copy_to_host_async()
-        # host-side build+dispatch span (the device runs free; its time
-        # surfaces in the matching paged.drain span)
-        otrace.complete("paged.dispatch", t_disp, time.perf_counter(),
-                        slots=len(plan), steps=D)
+        with otrace.span("paged.launch", slots=len(plan), steps=D):
+            if n_fresh:
+                # one fixed-shape invalidation for every page freshly
+                # allocated this block — stale (pos, kv) from a previous
+                # occupant would otherwise pass the causal mask
+                self.caches = self._invalidate(self.caches,
+                                               jnp.asarray(fresh))
+            base = self.sched.step
+            self.sched.step += D
+            toks, lps, self.caches, self.logits, self._done = self._decode(
+                self.params, self.caches, self.logits, self._done,
+                jnp.asarray(keys), jnp.asarray(wsl), jnp.asarray(valid),
+                jnp.asarray(rows), jnp.asarray(pos0), jnp.asarray(active),
+                jnp.asarray(self._ptab))
+            self.decode_steps += D
+            # start the device->host transfer NOW so it overlaps the next
+            # block's build + compute; the drain then finds it landed
+            for buf in (toks, lps):
+                if hasattr(buf, "copy_to_host_async"):
+                    buf.copy_to_host_async()
+        self.decode_launch_s += time.perf_counter() - t_launch
         return _Block(plan=plan, base=base, toks=toks, lps=lps)
 
     def _drain_block(self, blk: _Block) -> None:
         """Commit one drained block into host bookkeeping — the ONLY
         device->host touch of the non-spec decode path, once per D steps
         (or per row completion) instead of per token."""
-        t_drain = time.perf_counter()
-        g0 = self.generated_tokens
-        # repro: allow(host-sync): one buffered readback per drained
-        # D-step block (transfer started async at dispatch), not per
-        # token — DESIGN.md §Device-resident-decode drain protocol
-        toks, lps = jax.device_get((blk.toks, blk.lps))
-        for s, row, t0, n_row in blk.plan:
-            if self.sched.slot_req[s] is not row:
-                # row finished inside an EARLIER block; these optimistic
-                # steps ran device-masked (done flag) and wrote nothing
-                continue
-            g = row.group
-            assert len(row.toks) == t0, "drain out of order"
-            for j in range(n_row):
-                tv = int(toks[j, s])
-                row.toks.append(tv)
-                if self.capture_logprobs:
-                    row.lps.append(float(lps[j, s]))
-                self.generated_tokens += 1
-                if g.on_token is not None:
-                    g.on_token(row.idx, tv)
-                if tv == self.eos_id or len(row.toks) >= g.max_new:
-                    self._finish_row(s, row, blk.base + j + 1)
-                    break
-        otrace.complete("paged.drain", t_drain, time.perf_counter(),
-                        slots=len(blk.plan),
-                        tokens=self.generated_tokens - g0)
-        self._push_block_metrics()
+        t_wait = time.perf_counter()
+        with otrace.span("paged.wait"):
+            # repro: allow(host-sync): one buffered readback per drained
+            # D-step block (transfer started async at dispatch), not per
+            # token — DESIGN.md §Device-resident-decode drain protocol
+            toks, lps = jax.device_get((blk.toks, blk.lps))
+        t_commit = time.perf_counter()
+        self.decode_wait_s += t_commit - t_wait
+        with otrace.span("paged.commit", slots=len(blk.plan)):
+            for s, row, t0, n_row in blk.plan:
+                if self.sched.slot_req[s] is not row:
+                    # row finished inside an EARLIER block; these
+                    # optimistic steps ran device-masked (done flag) and
+                    # wrote nothing
+                    continue
+                g = row.group
+                assert len(row.toks) == t0, "drain out of order"
+                for j in range(n_row):
+                    tv = int(toks[j, s])
+                    row.toks.append(tv)
+                    if self.capture_logprobs:
+                        row.lps.append(float(lps[j, s]))
+                    self.generated_tokens += 1
+                    if g.on_token is not None:
+                        g.on_token(row.idx, tv)
+                    if tv == self.eos_id or len(row.toks) >= g.max_new:
+                        self._finish_row(s, row, blk.base + j + 1)
+                        break
+            self._push_block_metrics()
+        self.decode_host_s += time.perf_counter() - t_commit
 
     def _push_block_metrics(self) -> None:
         """Flush block-granularity deltas into the metrics registry (one
@@ -1122,19 +1173,21 @@ class PagedGroupEngine:
             keys[s] = g.keys[rc]
             folds[s] = row.idx
             fresh_m[s] = row.fresh
+        t_verify = time.perf_counter()
         if n_fresh:
             self.caches = self._invalidate(self.caches,
                                            jnp.asarray(fresh_pages))
-        t_verify = time.perf_counter()
         ctoks, clps, count, self.caches = self._verify(
             self.params, self.caches, self.logits, jnp.asarray(tokens),
             jnp.asarray(positions), jnp.asarray(segs), jnp.asarray(wslots),
             jnp.asarray(self._ptab), jnp.asarray(keys), jnp.asarray(folds),
             jnp.asarray(fresh_m), jnp.asarray(drafts))
+        t_launched = time.perf_counter()
+        self.decode_host_s += t_verify - t_draft
+        self.decode_launch_s += t_launched - t_verify
         # host-side dispatch only — the verify block's device time (and
         # its one buffered readback) lands inside the spec.commit span
-        otrace.complete("spec.verify", t_verify, time.perf_counter(),
-                        slots=len(act))
+        otrace.complete("spec.verify", t_verify, t_launched, slots=len(act))
         self._commit_spec_rows(act, ctoks, clps, count)
         return True
 
@@ -1148,6 +1201,8 @@ class PagedGroupEngine:
         t_commit = time.perf_counter()
         g0 = self.generated_tokens
         ctoks, clps, count = self._drain_verify(ctoks, clps, count)
+        t_drained = time.perf_counter()
+        self.decode_wait_s += t_drained - t_commit
         step = self.sched.tick()
         self.decode_steps += 1
         for s in act:
@@ -1179,11 +1234,13 @@ class PagedGroupEngine:
                 # hold only rejected drafts — roll them back
                 self._rollback_row(s, row, len(row.toks) - 2)
         committed = self.generated_tokens - g0
-        otrace.complete("spec.commit", t_commit, time.perf_counter(),
-                        slots=len(act), tokens=committed)
         self._m_drafted.add(k * len(act))
         self._m_accepted.add(max(0, committed - len(act)))
         self._push_block_metrics()
+        t_end = time.perf_counter()
+        self.decode_host_s += t_end - t_drained
+        otrace.complete("spec.commit", t_commit, t_end, slots=len(act),
+                        tokens=committed)
 
     # -- standalone serving -------------------------------------------------
 

@@ -381,7 +381,8 @@ class PeriodicAsyncScheduler:
                 otrace.complete("iteration", it_start, it_start + wall,
                                 iteration=start + t, mode=mode)
                 # per-iteration engine-stat deltas (spec acceptance, prefix
-                # hit rate, page reclamation) surfaced through the same
+                # hit rate, page reclamation, decode steps and the host
+                # seconds of their phases) surfaced through the same
                 # metrics path as sync_gap — zero when no paged engine runs
                 engine1 = pool.engine_stats()
                 d = {k: engine1[k] - engine0[k] for k in engine1}
@@ -413,6 +414,11 @@ class PeriodicAsyncScheduler:
                              "spec_acceptance": spec_acceptance,
                              "prefix_hit_rate": prefix_hit_rate,
                              "pages_reclaimed": d["reclaimed_pages"],
+                             "decode_steps": d["decode_steps"],
+                             "decode_host_s": d["decode_host_s"],
+                             "decode_launch_s": d["decode_launch_s"],
+                             "decode_wait_s": d["decode_wait_s"],
+                             "decode_gap_s": d["decode_gap_s"],
                              # repro: allow(host-sync): once per iteration,
                              # after the update has materialised
                              "loss": float(np.mean(jax.device_get(

@@ -10,7 +10,13 @@ Design constraints, in priority order:
 * **Near-zero overhead when disabled.** Instrumentation sites call the
   module-level ``span()``/``complete()``/``instant()`` facade; with no
   tracer installed these are one global load + a ``None`` check (and
-  ``span()`` returns a shared no-op context manager). Nothing allocates.
+  ``span()`` returns a shared no-op context manager, after one
+  ``TraceMe.is_enabled()`` call). Nothing allocates.
+* **On the profiler's clock.** While a JAX profiler session records,
+  ``span()`` also opens a ``jax.profiler.TraceAnnotation`` of the same
+  name, installed tracer or not, so host spans land in the
+  ``.xplane.pb`` beside the device's ops. Retro-recorded ``complete()``
+  events cannot: the profiler only takes spans as they happen.
 * **No clock of its own on the hot tier.** Span timestamps reuse the
   clock reads the pipeline already takes (the deferred busy-settle
   clock, the boundary stopwatches) via ``complete(name, t0, t1)``; the
@@ -56,6 +62,12 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+from jaxlib._profiler import TraceMe
+
+# True while a profiler session records (one C++ flag read)
+_profiling = TraceMe.is_enabled
+
 
 class _NullSpan:
     """Shared no-op context manager returned by ``span()`` when tracing
@@ -73,6 +85,35 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+class _ProfiledSpan:
+    """``span()`` while a profiler session records: a ``TraceAnnotation``
+    on the profiler's clock around the installed tracer's own span, if
+    any."""
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, name: str, attrs: Dict[str, Any],
+                 span: Optional["_Span"]):
+        self._ann = TraceAnnotation(name, **attrs)
+        self._span = span
+
+    def __enter__(self) -> "_ProfiledSpan":
+        self._ann.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def set(self, **attrs) -> "_ProfiledSpan":
+        if self._span is not None:
+            self._span.set(**attrs)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
 
 
 class _Span:
@@ -387,6 +428,9 @@ def active() -> bool:
 
 def span(name: str, **attrs):
     t = _active
+    if _profiling():
+        return _ProfiledSpan(name, attrs,
+                             None if t is None else t.span(name, **attrs))
     if t is None:
         return _NULL_SPAN
     return t.span(name, **attrs)

@@ -285,10 +285,17 @@ def test_histogram_buckets_cumulative_and_consistent():
 # streaming trace export: segments == monolithic, bounded peak memory
 # ---------------------------------------------------------------------------
 
+# A fixed epoch for scripted events: ``(e + off) - e`` rounds by e's low
+# bits, so two tracers with their own perf_counter epochs would export
+# timestamps that differ in the last digits (most often on a host whose
+# clock reads only tens of seconds since boot).
+_EPOCH = 137.0390625
+
+
 def _script_pipeline_events(tr):
-    """Deterministic span set (offsets from the tracer's own epoch) —
-    identical input to a monolithic and a streaming tracer."""
-    e = tr._epoch
+    """Deterministic span set (offsets from one pinned epoch) — identical
+    timestamps in a monolithic and a streaming tracer."""
+    tr._epoch = e = _EPOCH
 
     def one_iter(i):
         lo = e + i * 1.0
