@@ -370,16 +370,28 @@ class RLConfig:
     spec_draft: str = "prompt_lookup"
     spec_ngram: int = 3                # longest n-gram the lookup tries
     # --- device-resident decode (DESIGN.md §Device-resident-decode) ---
-    # Steps fused per jitted decode block in the paged/cbatch engines:
+    # Steps D fused per jitted decode block of the paged rollout engine:
     # tokens, EOS flags and logprobs accumulate in device buffers for D
     # steps and drain to Python once per block (double-buffered — block
     # n+1 dispatches before block n's readback lands), so the hot loop
     # never blocks on a per-token device_get. 1 = drain every step
     # (legacy cadence, bitwise-identical admission/eviction timing).
     # The paged engine is sampled-identical for every D (per-row step
-    # keys); cbatch D>1 realigns the sampled key chain (greedy identical
-    # for every D) — see core/cbatch.py.
-    decode_drain_interval: int = 1
+    # keys).
+    # 8 from a sweep of D in {1, 4, 8, 16, 32} on one TPU v5e (PERF.md
+    # §6): at D=1 the host's launch of each one-token block (2.4 ms) is
+    # as long as the block's device time (2.5 ms) and the chip idles 69%;
+    # D=8 launches once per 8 tokens, and a step's copies shrink (the
+    # weights' relayout runs once a block), so a step costs 1.66 ms of
+    # device time and GRPO tokens/s per device doubles (8,470 -> 17,540).
+    # D=8 is the smallest D within 1% of the best in both GRPO cells;
+    # D=16 and 32 lose, since an iteration's groups land in about 3
+    # consecutive blocks and each block of lag costs D steps. RL
+    # rollouts can take a long block: their groups all arrive at the
+    # iteration's start, and the engine is quiescent at every weight
+    # sync. The serving tier (launch/serve.py) keeps its own default of
+    # 1, since there D delays the admission of arrivals.
+    decode_drain_interval: int = 8
     # --- radix prefix cache (DESIGN.md §Radix-prefix-cache) -----------
     # Share cached prompt pages across requests with any common
     # token-span prefix (paged engine only): admission walks a radix

@@ -985,6 +985,13 @@ class PagedGroupEngine:
                 prev, self._pending = self._pending, nxt
                 if prev is not None:
                     self._drain_block(prev)
+                if self._pending is not None and self.sched.idle:
+                    # every row stopped inside ``prev``: the block behind it
+                    # holds only device-masked steps. Drain it now, so the
+                    # engine is quiescent (and the gap clock stops) when the
+                    # last handle resolves, ahead of the weight sync
+                    self._drain_block(self._pending)
+                    self._pending = None
                 ran = nxt is not None or prev is not None
             self._step_end = (None if self.sched.idle
                               and self._pending is None
@@ -1051,11 +1058,15 @@ class PagedGroupEngine:
                                                jnp.asarray(fresh))
             base = self.sched.step
             self.sched.step += D
+            # the page table goes up as a snapshot: the host edits _ptab in
+            # place (drain, admission, the next build) while this block
+            # may still run, and a backend that wraps host memory without
+            # a copy (the CPU's) would let the block read those edits
             toks, lps, self.caches, self.logits, self._done = self._decode(
                 self.params, self.caches, self.logits, self._done,
                 jnp.asarray(keys), jnp.asarray(wsl), jnp.asarray(valid),
                 jnp.asarray(rows), jnp.asarray(pos0), jnp.asarray(active),
-                jnp.asarray(self._ptab))
+                jnp.asarray(self._ptab.copy()))
             self.decode_steps += D
             # start the device->host transfer NOW so it overlaps the next
             # block's build + compute; the drain then finds it landed

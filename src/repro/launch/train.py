@@ -140,10 +140,12 @@ def main(argv=None) -> None:
     ap.add_argument("--cbatch-slots", type=int, default=8,
                     help="decode slots per paged instance")
     ap.add_argument("--kv-page-size", type=int, default=16)
-    ap.add_argument("--drain-interval", type=int, default=1,
+    ap.add_argument("--drain-interval", type=int,
+                    default=RLConfig().decode_drain_interval,
                     help="fused decode-block length D for the paged engine "
                          "(DESIGN.md §Device-resident-decode): the host "
                          "drains device token buffers once per D steps; "
+                         "defaults to RLConfig's decode_drain_interval; "
                          "1 = legacy per-token cadence")
     ap.add_argument("--spec", action="store_true",
                     help="speculative decode for rollouts (DESIGN.md "

@@ -38,8 +38,10 @@ def cfg():
     return reduced_config(get_config("llama3.2-3b"))
 
 
-# the per-token drain, the pipelined fused drain, and speculative decode
-ENGINES = {"drain1": {}, "drain4": {"decode_drain_interval": 4},
+# the per-token drain, the pipelined fused drain (at 4 and at RLConfig's
+# default), and speculative decode
+ENGINES = {"drain1": {"decode_drain_interval": 1},
+           "drain4": {"decode_drain_interval": 4}, "default": {},
            "spec": {"spec_decode": True, "spec_k": 2}}
 
 

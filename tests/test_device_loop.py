@@ -225,6 +225,63 @@ def test_paged_eos_mid_block_and_block_boundary(setups):
         assert len(fused) == want and fused[-1] == tok
 
 
+@pytest.mark.parametrize("t_star", [4, 5])
+def test_paged_last_row_eos_leaves_no_block_in_flight(setups, t_star):
+    """The last row stops by EOS inside a block (mid-block, and on its
+    last step) while the next block is already dispatched: when its handle
+    resolves, as a driving instance sees it, that masked block has been
+    drained too, so the engine is quiescent for the weight sync and a
+    further step finds nothing to do."""
+    cfg, params = setups["gqa"]
+    key = jax.random.PRNGKey(4)
+
+    def engine(eos_id, drain):
+        eng = _engine(cfg, group_size=1, num_slots=1, temperature=0.0,
+                      eos_id=eos_id, drain_interval=drain)
+        eng.set_params(params)
+        return eng
+
+    stream = list(engine(-1, 1).serve(params, [PROMPT], key)[0]
+                  .response_ids)
+    tok = stream[t_star]
+    want = stream[: stream.index(tok) + 1]
+    assert len(want) > 3          # the stop falls in the second block
+    eng = engine(tok, 3)
+    h = eng.submit(PROMPT, key)
+    while not h.done():
+        eng.step()
+    assert list(np.asarray(h.host_rows()[0])) == want
+    assert eng._pending is None and eng.idle
+    assert not eng.step()
+
+
+def test_paged_block_page_table_is_a_snapshot(setups):
+    """The host edits its page table (new pages, evictions, admissions)
+    while a pipelined block is still in flight; the table the block was
+    given must keep the rows it was dispatched with. The CPU backend
+    wraps a 64-byte-aligned host array without a copy, so the engine's
+    table is moved to such an address first: an upload of the live table
+    would then change under the block."""
+    cfg, params = setups["gqa"]
+    eng = _engine(cfg, temperature=1.0, drain_interval=3)
+    buf = np.zeros(eng._ptab.nbytes + 64, np.uint8)
+    off = -buf.ctypes.data % 64
+    eng._ptab = buf[off:off + eng._ptab.nbytes].view(np.int32).reshape(
+        eng._ptab.shape)
+    tables = []
+    decode = eng._decode
+
+    def recorded(*args):
+        tables.append((args[-1], np.array(args[-1])))
+        return decode(*args)
+
+    eng._decode = recorded
+    _run_group(eng, params, PROMPT, jax.random.PRNGKey(8))
+    assert len(tables) > 2
+    for sent, at_dispatch in tables:
+        np.testing.assert_array_equal(np.asarray(sent), at_dispatch)
+
+
 def test_paged_slot_reassignment_during_stale_drain(setups):
     """More requests than slots with a large D: a row finishing inside an
     earlier block frees its slot while a later optimistic block for that
