@@ -4,6 +4,8 @@ configuration and traffic are found by name, and seeds.
 Everything a cell needs is looked up by the names in ``BENCHMARK.json``:
 
 * ``bench/configs/<config>.json``  — the configuration as it is run;
+* ``bench/reference/<reference>.py`` — the plain reference of the
+  architecture that the configuration's ``reference`` names;
 * ``bench/traffic/<traffic>.json`` — the traffic mix, whose ``kind`` names
   the generator and driver in ``bench/kinds/<kind>.py``;
 * ``bench/metrics/<metric>.py``    — one reader per per-layer metric;
@@ -12,6 +14,7 @@ Everything a cell needs is looked up by the names in ``BENCHMARK.json``:
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import pathlib
@@ -81,6 +84,18 @@ def kind_module(kind: str):
 def metric_reader(name: str):
     return load_module(BENCH / "metrics" / f"{name}.py",
                        "bench_metric_" + name.replace(".", "_"))
+
+
+def reference_module(name: str):
+    """The plain reference of an architecture, ``bench/reference/<name>.py``
+    (its interface is in ``bench/reference/__init__.py``). A name with no
+    file is an error, never a fallback to another reference."""
+    path = BENCH / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"no reference {name!r}: the configuration names {path}, which "
+            f"does not exist")
+    return importlib.import_module(f"bench.reference.{name}")
 
 
 def model_config(spec: dict, *, rehearse: bool = False):

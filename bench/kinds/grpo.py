@@ -18,6 +18,10 @@ Set-up builds the pipeline with the seed's weights and drives it through
 iterations compile every program, and the plain reference follows them
 after the window. The window runs whole iterations until ``--seconds``
 have passed; a traced run then traces ``TRACE_ITERS`` more.
+
+Whatever depends on the architecture (the sizes, the weights' layout, the
+reference's loss terms and the required work) comes from the module that
+the configuration's ``reference`` names (``bench/reference/__init__.py``).
 """
 from __future__ import annotations
 
@@ -27,19 +31,12 @@ import types
 
 import numpy as np
 
-from bench import common, flops, weights
+from bench import common, weights
 
 REHEARSE = dict(prompt_len=24, max_response_len=16, group_size=4,
                 rollout_slots=8)
 # a traced run traces this many iterations after the window
 TRACE_ITERS = 2
-
-
-def dims_from_program(cfg) -> dict:
-    return dict(d=cfg.d_model, H=cfg.num_heads, Hkv=cfg.num_kv_heads,
-                hd=cfg.head_dim, ff=cfg.d_ff, V=cfg.vocab_size,
-                L=cfg.num_layers, eps=cfg.norm_eps,
-                theta=float(cfg.rope_theta), dtype=cfg.param_dtype)
 
 
 def parity_reward(response_ids, answer=None) -> float:
@@ -111,7 +108,6 @@ def run(ctx) -> dict:
     import jax
     common.add_program_to_path()
     from repro.models import init
-    from bench.reference import dense_gqa as ref_model
     from bench.reference import grpo as ref_grpo
     from bench.reference.numerics import F32
     from bench.harness import free_device_memory
@@ -120,14 +116,18 @@ def run(ctx) -> dict:
     if t["reward"] != "parity":
         raise ValueError(f"unknown reward {t['reward']!r}; this generator "
                          f"knows 'parity'")
+    ref_model = common.reference_module(spec["reference"])
     cfg = common.model_config(spec, rehearse=ctx.rehearse)
     if ctx.rehearse:
         t.update(REHEARSE)
-        dims = dims_from_program(cfg)
+        dims = ref_model.dims_from_program(cfg)
     else:
         dims = ref_model.dims_from_spec(spec)
-        assert dims == dims_from_program(cfg), (dims, dims_from_program(cfg))
-    ctx.cfg_vocab = cfg.vocab_size
+        prog = ref_model.dims_from_program(cfg)
+        if dims != prog:
+            raise ValueError(f"the configuration file's sizes {dims} differ "
+                             f"from the program's {prog}")
+    ctx.cfg_vocab = dims["V"]
     trainer = dict(spec["trainer"])
     layout = ref_model.layout(dims)
     weights.check_layout(layout, jax.eval_shape(
@@ -155,8 +155,9 @@ def run(ctx) -> dict:
     keep = kept_leaves(ref)
     by_leaf = lambda prog, want: dict(zip(names, (
         float(x) for x in leaf_gaps(prog, want, keep))))
-    controls = {c: control_numbers(c, follow, ref, rollouts, V=cfg.vocab_size)
+    controls = {c: control_numbers(c, follow, ref, rollouts, V=dims["V"])
                 for c in ctx.controls}
+    work = ref_model.window_work(dims, sizes, wv, t, out["counters"])
 
     hist = out["hist"]
     tokens = sum(lp_ + r for v, grp in sizes.items() if v in wv
@@ -171,7 +172,7 @@ def run(ctx) -> dict:
                 "setup_s": out["setup_s"]},
         "numbers": numbers, "memory_peak": out["memory_peak"],
         "hist": hist, "decode_steps_traced": out["decode_steps_traced"],
-        "work": window_work(dims, sizes, wv, t),
+        "work": work,
         "chips": ctx.chips,
         "info": {"iterations": len(hist), "tokens": tokens,
                  "setup_compiles": out["setup_compiles"],
@@ -181,7 +182,7 @@ def run(ctx) -> dict:
                  "ref_loss": ref["loss"], "prog_loss": out["prog_loss"],
                  "ref_grad_norm": ref["grad_norm"][0],
                  "prog_grad_norm": out["prog_gnorm"],
-                 "decode_steps": out["decode_steps"],
+                 "decode_steps": out["decode_steps"], "work": work,
                  "first_grad_leaf_gaps": by_leaf(out["first_grad"],
                                                  ref["clipped_leaf_norms"]),
                  "groups": group_summary(rollouts),
@@ -243,7 +244,7 @@ def _program(ctx, cfg, t, trainer, layout) -> dict:
     finally:
         train_mod.init = made
     del params
-    sched.loader = Loader(t, cfg.vocab_size, ctx.seed)
+    sched.loader = Loader(t, ctx.cfg_vocab, ctx.seed)
     if not t["stop_at_eos"]:
         # an id that is never sampled: the engine reads it when its decode
         # block is first traced, in set-up. A random policy samples EOS at
@@ -302,14 +303,14 @@ def _program(ctx, cfg, t, trainer, layout) -> dict:
     # -- window -----------------------------------------------------------
     ctx.clock.reset()
     k0 = len(sched.history)
-    steps0 = pool.engine_stats()["decode_steps"]
+    eng0 = pool.engine_stats()
     t0 = time.perf_counter()
     while time.perf_counter() - t0 < ctx.seconds:
         sched.run(1)
     window_s = time.perf_counter() - t0
     window_compiles = ctx.clock.count
     hist = sched.history[k0:]
-    decode_steps = pool.engine_stats()["decode_steps"] - steps0
+    counters = window_counters(eng0, pool.engine_stats(), hist)
     steps_traced = None
     if ctx.trace:
         # traced iterations follow the window, so tracing slows none of the
@@ -325,7 +326,8 @@ def _program(ctx, cfg, t, trainer, layout) -> dict:
         "setup_compiles": setup_compiles, "setup_compile_s": setup_compile_s,
         "window_compiles": window_compiles,
         "memory_peak": memory_peak(jax.devices()),
-        "decode_steps": decode_steps, "decode_steps_traced": steps_traced,
+        "decode_steps": counters["engine"]["decode_steps"],
+        "decode_steps_traced": steps_traced, "counters": counters,
         "hist": [dataclasses.asdict(s) for s in hist],
         "sizes": sizes, "wv": {s.iteration for s in hist},
         "staleness": max(s.max_staleness for s in setup_hist + hist),
@@ -337,25 +339,16 @@ def _program(ctx, cfg, t, trainer, layout) -> dict:
     }
 
 
-def window_work(dims, sizes, wv, t) -> dict:
-    """Required FLOPs of the window's iterations: training counts policy
-    forward and backward (6N) and the reference forward (2N) over each
-    group's prompt once and every response token, rollout counts 2N per
-    prompt token per group and per generated token, each with attention
-    over the token's context."""
-    per_tok = 2 * flops.dense_matmul_params(dims)
-    att = lambda ctx_sum: flops.dense_attention_flops(dims, ctx_sum)
-    train = roll = 0
-    for v, grp in sizes.items():
-        if v not in wv:
-            continue
-        for lp_, rs in grp:
-            p_ctx = flops.causal_sum(lp_)
-            r_ctx = sum(flops.causal_sum(r, lp_) for r in rs)
-            n_tok = lp_ + sum(rs)
-            train += 4 * (per_tok * n_tok + att(p_ctx + r_ctx))
-            roll += per_tok * n_tok + att(p_ctx + r_ctx)
-    return {"train_flops": train, "rollout_flops": roll}
+def window_counters(eng0, eng1, hist) -> dict:
+    """The program's counters over the window: the engine's, as the change
+    of ``engine_stats`` across it, and the trainer's, as its iterations'
+    ``IterationStats.metrics`` summed."""
+    trainer = {}
+    for s in hist:
+        for k, v in s.metrics.items():
+            trainer[k] = trainer.get(k, 0) + v
+    return {"engine": {k: eng1[k] - eng0[k] for k in eng1},
+            "trainer": trainer}
 
 
 def kept_leaves(ref) -> np.ndarray:

@@ -5,13 +5,15 @@ untied output head. No cache, no packing, no kernels.
 
 Parameters follow the layout of ``layout`` (matrices stored (in, out),
 layers stacked on a leading axis); they are the bfloat16 values the
-benchmark drew from the seed, read as float32.
+benchmark drew from the seed, read as float32. The module implements the
+interface of ``bench/reference/__init__.py``; it has no auxiliary loss.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from bench import flops
 from bench.reference.numerics import (causal_softmax_attention, rmsnorm,
                                       rope)
 
@@ -23,6 +25,13 @@ def dims_from_spec(spec: dict) -> dict:
                 L=spec["num_hidden_layers"], eps=spec["rms_norm_eps"],
                 theta=float(spec["rope_theta"]),
                 dtype=spec.get("torch_dtype", "bfloat16"))
+
+
+def dims_from_program(cfg) -> dict:
+    return dict(d=cfg.d_model, H=cfg.num_heads, Hkv=cfg.num_kv_heads,
+                hd=cfg.head_dim, ff=cfg.d_ff, V=cfg.vocab_size,
+                L=cfg.num_layers, eps=cfg.norm_eps,
+                theta=float(cfg.rope_theta), dtype=cfg.param_dtype)
 
 
 def layout(m: dict) -> dict:
@@ -92,3 +101,25 @@ def next_token_logprobs(num, m, params, tokens):
     lg = logits(num, m, params, tokens)[:-1]
     lp = jax.nn.log_softmax(lg, axis=-1)
     return jnp.take_along_axis(lp, tokens[1:, None], axis=-1)[:, 0]
+
+
+def window_work(m, sizes, wv, t, counters) -> dict:
+    """Required FLOPs of the window's iterations: training counts policy
+    forward and backward (6N) and the reference forward (2N) over each
+    group's prompt once and every response token, rollout counts 2N per
+    prompt token per group and per generated token, each with attention
+    over the token's context. A dense model needs no counter of the run."""
+    del t, counters
+    per_tok = 2 * flops.dense_matmul_params(m)
+    att = lambda ctx_sum: flops.dense_attention_flops(m, ctx_sum)
+    train = roll = 0
+    for v, grp in sizes.items():
+        if v not in wv:
+            continue
+        for lp_, rs in grp:
+            p_ctx = flops.causal_sum(lp_)
+            r_ctx = sum(flops.causal_sum(r, lp_) for r in rs)
+            n_tok = lp_ + sum(rs)
+            train += 4 * (per_tok * n_tok + att(p_ctx + r_ctx))
+            roll += per_tok * n_tok + att(p_ctx + r_ctx)
+    return {"train_flops": train, "rollout_flops": roll}

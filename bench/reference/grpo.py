@@ -7,7 +7,11 @@
 * loss: per sample the token mean over its response of
   min(rho A, clip(rho, 1-eps_l, 1+eps_h) A) - beta KL_k3(ref || policy),
   averaged over the iteration's samples and negated. The run is on-policy
-  (the old policy is the policy), so rho = exp(logp - stop_gradient(logp));
+  (the old policy is the policy), so rho = exp(logp - stop_gradient(logp)).
+  Where the architecture's module has ``aux_loss``, each sample adds
+  ``aux_coef * aux_loss / N``: the program adds the term to each
+  micro-batch's loss and weighs the micro-batch's gradient by its samples
+  over the iteration's N, and a micro-batch of one sample weighs 1/N;
 * update: AdamW with global-norm clipping, moments in float32, parameters
   stored in the configuration's dtype.
 
@@ -63,10 +67,12 @@ def _response_logprobs(model, num, m, params, tokens, Lp, Lr):
 @partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6), donate_argnums=(13,))
 def _micro(model, num, mk, tk, Lp, Lr, same_ref, policy, ref, tokens, lens,
            adv, n_total, gsum):
-    """Add the float32 gradient of this micro-batch's share of -J to
-    ``gsum``; return the loss share, the response logprobs and the sum.
-    The gradient is taken at the float32 values of the stored weights."""
+    """Add the float32 gradient of this micro-batch's share of -J (and of
+    the auxiliary term, where the model has one) to ``gsum``; return the
+    loss share, the response logprobs and the sum. The gradient is taken at
+    the float32 values of the stored weights."""
     m, t = dict(mk), dict(tk)
+    aux_loss = getattr(model, "aux_loss", None)
     mask = (jnp.arange(Lr)[None, :] < lens[:, None]).astype(jnp.float32)
     w = mask / jnp.maximum(lens[:, None], 1).astype(jnp.float32)
     # the policy of the first iteration is the reference model itself
@@ -82,7 +88,10 @@ def _micro(model, num, mk, tk, Lp, Lr, same_ref, policy, ref, tokens, lens,
         d = (jax.lax.stop_gradient(lp) if same_ref else lp_ref) - lp
         kl = jnp.exp(d) - d - 1.0
         j = jnp.sum((surr - t["kl_coef"] * kl) * w) / n_total
-        return -j, lp
+        if aux_loss is None:
+            return -j, lp
+        aux = jax.vmap(lambda s: aux_loss(num, m, p, s))(tokens)
+        return -j + m["aux_coef"] * jnp.sum(aux) / n_total, lp
 
     p32 = jax.tree.map(lambda x: x.astype(jnp.float32), policy)
     (l, lp), g = jax.value_and_grad(loss, has_aux=True)(p32)

@@ -1,7 +1,9 @@
-"""A configuration, a traffic mix and a per-layer metric are added as new
-files plus new entries in BENCHMARK.json, with no edit to an existing
-file, and the harness finds each by its name. Also: without a chip, or
-without the program beside it, a run exits non-zero and prints no result.
+"""A configuration, a traffic mix, a per-layer metric and the plain
+reference of a new architecture are added as new files plus new entries in
+BENCHMARK.json, with no edit to an existing file, and the harness finds
+each by its name. Also: without a chip, or without the program beside it,
+or with a configuration whose reference has no file, a run exits non-zero
+and prints no result.
 """
 import json
 import os
@@ -28,10 +30,45 @@ def _run(root, *args, timeout=900):
                           capture_output=True, text=True, timeout=timeout)
 
 
-def _add_cell(root: pathlib.Path, with_limits: bool = True) -> None:
+# a second architecture as a new file only: dense GQA with a seeded
+# auxiliary term that moves no gradient, and a work count that marks itself
+STAND_IN = """
+import jax.numpy as jnp
+import numpy as np
+
+from bench.reference import dense_gqa
+from bench.reference.dense_gqa import layout, next_token_logprobs  # noqa
+
+AUX = float(np.random.default_rng(15).uniform(1.0, 2.0))
+AUX_COEF = 0.5
+
+
+def dims_from_spec(spec):
+    return dict(dense_gqa.dims_from_spec(spec), aux_coef=AUX_COEF)
+
+
+def dims_from_program(cfg):
+    return dict(dense_gqa.dims_from_program(cfg), aux_coef=AUX_COEF)
+
+
+def aux_loss(num, m, params, tokens):
+    return jnp.float32(AUX)
+
+
+def window_work(m, sizes, wv, t, counters):
+    return dict(dense_gqa.window_work(m, sizes, wv, t, counters),
+                reference="stand_in", engine_steps=counters["engine"][
+                    "decode_steps"])
+"""
+
+
+def _add_cell(root: pathlib.Path, with_limits: bool = True,
+              reference: str = "") -> None:
     """A configuration, a traffic mix, a metric and the cell's limits, as
-    new files and new entries only."""
+    new files and new entries only; the configuration names ``reference``
+    where one is given."""
     spec = common.config_spec("internlm2-20b-l1v8")
+    spec["reference"] = reference or spec["reference"]
     (root / "bench/configs/internlm2-copy.json").write_text(json.dumps(spec))
     mix = common.traffic_spec("grpo_async_g8")
     mix.update(mode="sync", prompts_per_iteration=2)
@@ -90,6 +127,48 @@ def test_new_cell_without_limits_fails(tmp_path):
     r = _rehearse(root, "grpo-sync-copy")
     assert r.returncode != 0
     assert "no limits for 'grpo-sync-copy'" in r.stderr
+    assert "correct=True" not in r.stderr
+
+
+def _rehearsal_line(stderr: str) -> dict:
+    return json.loads(stderr.split("correct=True; ", 1)[1].splitlines()[0])
+
+
+def test_new_architecture_from_files_only(tmp_path):
+    """The configuration's ``reference`` selects the module: its sizes, its
+    auxiliary term (which shifts the reference's loss by ``aux_coef * aux``
+    each iteration and leaves the gradients as they are) and its work."""
+    root = tmp_path / "checkout"
+    _copy_checkout(root)
+    before = {p: p.read_bytes() for p in (root / "bench").rglob("*")
+              if p.is_file()}
+    (root / "bench/reference/stand_in.py").write_text(STAND_IN)
+    _add_cell(root, reference="stand_in")
+    r = _rehearse(root, "grpo-sync-copy")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "rehearsal finished, correct=True" in r.stderr, r.stderr[-3000:]
+    info = _rehearsal_line(r.stderr)["info"]
+    assert info["work"]["reference"] == "stand_in"
+    assert info["work"]["engine_steps"] == info["decode_steps"] > 0
+    assert info["work"]["train_flops"] > 0
+    r = _run(root, "-c", "from bench.reference import stand_in as s; "
+             "print(s.AUX_COEF * s.AUX)")
+    shift = float(r.stdout)
+    assert len(info["ref_loss"]) == 3
+    for ref_loss, prog_loss in zip(info["ref_loss"], info["prog_loss"]):
+        assert abs(ref_loss - prog_loss - shift) < 1e-4, (ref_loss,
+                                                          prog_loss, shift)
+    after = {p: p.read_bytes() for p in before}
+    assert after == before                      # no existing file edited
+
+
+def test_unknown_reference_is_an_error(tmp_path):
+    root = tmp_path / "checkout"
+    _copy_checkout(root)
+    _add_cell(root, reference="no_such_arch")
+    r = _rehearse(root, "grpo-sync-copy")
+    assert r.returncode != 0
+    assert "bench/reference/no_such_arch.py" in r.stderr, r.stderr[-3000:]
     assert "correct=True" not in r.stderr
 
 

@@ -18,7 +18,7 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-from bench import common  # noqa: E402
+from bench import common, weights  # noqa: E402
 
 
 def _report(name, fn, *args):
@@ -63,8 +63,11 @@ def main() -> None:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     dev = SingleDeviceSharding(topo.devices[0])
-    from repro.models import init
-    pshapes = jax.eval_shape(lambda k: init(k, cfg), jax.random.PRNGKey(0))
+    # the tree the benchmark hands the program: the layout of the reference
+    # that the configuration names
+    ref = common.reference_module(spec["reference"])
+    pshapes = jax.eval_shape(lambda: weights.make(
+        ref.layout(ref.dims_from_spec(spec)), 0))
 
     def sds(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
