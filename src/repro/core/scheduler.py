@@ -120,9 +120,11 @@ class PeriodicAsyncScheduler:
         self._inflight: List = []
         self._key = None
         self._train_busy = 0.0
-        # per-iteration device scalars: micro-step losses and the update's
-        # pre-clip gradient norm, read once when the iteration's stats land
+        # per-iteration device scalars: micro-step losses and attention
+        # tile shares, and the update's pre-clip gradient norm, read once
+        # when the iteration's stats land
         self._losses: List = []
+        self._tile_shares: List = []
         self._grad_norm = None
         # registry metrics for the live ops plane (/metrics): cached
         # handles, pushed once per iteration at the boundary
@@ -197,6 +199,7 @@ class PeriodicAsyncScheduler:
                 grads, step_metrics = step(self.tri.policy, self.tri.old,
                                            self.tri.ref, mb)
                 self._losses.append(step_metrics["loss"])
+                self._tile_shares.append(step_metrics["attn_tile_share"])
                 # repro: allow(host-sync): trainer-side busy-time measurement
                 # barrier (paper Table 7 timing); not a decode path
                 jax.block_until_ready(jax.tree.leaves(grads)[0])
@@ -328,6 +331,7 @@ class PeriodicAsyncScheduler:
                 engine0 = pool.engine_stats()
                 self._train_busy = 0.0
                 self._losses = []
+                self._tile_shares = []
                 acc = GradAccumulator()
                 rewards_seen: List[float] = []
                 trained_tokens = 0
@@ -391,6 +395,10 @@ class PeriodicAsyncScheduler:
                 prefix_probes = d["prefix_hit_pages"] + d["prefix_miss_pages"]
                 prefix_hit_rate = (d["prefix_hit_pages"] / prefix_probes
                                    if prefix_probes else 0.0)
+                # repro: allow(host-sync): once per iteration, after the
+                # update has materialised
+                losses, tile_shares = jax.device_get(
+                    (self._losses, self._tile_shares))
                 stats = IterationStats(
                     iteration=start + t, wall_time=wall,
                     # producer busy-time delta over this iteration — in
@@ -419,10 +427,10 @@ class PeriodicAsyncScheduler:
                              "decode_launch_s": d["decode_launch_s"],
                              "decode_wait_s": d["decode_wait_s"],
                              "decode_gap_s": d["decode_gap_s"],
-                             # repro: allow(host-sync): once per iteration,
-                             # after the update has materialised
-                             "loss": float(np.mean(jax.device_get(
-                                 self._losses))),
+                             "loss": float(np.mean(losses)),
+                             # share of attention tiles the grad steps
+                             # computed (1.0 on the chunked path)
+                             "attn_tile_share": float(np.mean(tile_shares)),
                              "grad_norm": float(self._grad_norm)})
                 self.history.append(stats)
                 self._m_iteration.set(start + t + 1)

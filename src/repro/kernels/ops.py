@@ -20,7 +20,10 @@ from repro.kernels.decode_attention import (paged_verify_attention
                                             as _paged_verify)
 from repro.kernels.decode_attention import (paged_mla_verify_attention
                                             as _paged_mla_verify)
-from repro.kernels.spa_attention import spa_attention as _spa, block_map
+from repro.kernels.spa_attention import BLOCK_K, BLOCK_Q, block_map
+from repro.kernels.spa_attention import spa_attention as _spa
+# share of tiles ``spa_attention`` computes at the same tile sizes
+from repro.kernels.spa_attention import tile_share as spa_tile_share
 
 
 def auto_interpret() -> bool:
@@ -30,9 +33,10 @@ def auto_interpret() -> bool:
 def spa_attention(q, k, v, q_pos, kv_pos, q_seg, kv_seg, *,
                   scale: Optional[float] = None,
                   window: Optional[int] = None,
-                  block_q: int = 128, block_k: int = 128,
+                  block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
                   interpret: Optional[bool] = None):
-    """Block-sparse shared-prompt flash attention (see spa_attention.py)."""
+    """Block-sparse shared-prompt flash attention, differentiable in q, k
+    and v (see spa_attention.py)."""
     itp = auto_interpret() if interpret is None else interpret
     return _spa(q, k, v, q_pos, kv_pos, q_seg, kv_seg, scale=scale,
                 window=window, block_q=block_q, block_k=block_k,
@@ -116,7 +120,8 @@ def transfer_cast(x, dtype, *, block_rows: int = 256,
     return _transfer_cast(x, dtype, block_rows=block_rows, interpret=itp)
 
 
-__all__ = ["spa_attention", "decode_attention", "verify_attention",
-           "paged_decode_attention", "paged_mla_decode_attention",
-           "paged_verify_attention", "paged_mla_verify_attention",
+__all__ = ["spa_attention", "spa_tile_share", "decode_attention",
+           "verify_attention", "paged_decode_attention",
+           "paged_mla_decode_attention", "paged_verify_attention",
+           "paged_mla_verify_attention",
            "block_map", "auto_interpret", "transfer_cast"]

@@ -28,7 +28,8 @@ from repro.core import (InferenceInstance, InferencePool, PeriodicAsyncScheduler
 from repro.data.loader import PromptLoader
 from repro.data.tasks import ArithmeticTask
 from repro.data.tokenizer import Tokenizer
-from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.compile_cache import (canonicalize_source_paths,
+                                         enable_compile_cache)
 from repro.models import init
 from repro.obs import trace as otrace
 from repro.rl.reward import RuleBasedReward
@@ -118,6 +119,9 @@ def build_pipeline(cfg, rl: RLConfig, *, seed: int = 0, prompt_pad: int = 0,
             params, mesh, "infer_tp"))
     sched = PeriodicAsyncScheduler(cfg, rl, tri, gen, queue, loader,
                                    transfer=transfer)
+    # the grad step holds Pallas kernels (DESIGN.md §3): keep the
+    # checkout's path out of their persistent-cache keys
+    canonicalize_source_paths()
     return sched, {"tokenizer": tok, "task": task, "loader": loader,
                    "pool": pool, "queue": queue, "generator": gen,
                    "tri": tri, "transfer": transfer}

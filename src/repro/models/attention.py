@@ -475,6 +475,37 @@ def _shmap_gqa_decode(cfg: ModelConfig, q, k, v, positions, segments,
     return out, {"k": nk, "v": nv, "pos": npos, "seg": nseg}
 
 
+def _platform() -> str:
+    return jax.default_backend()
+
+
+def spa_kernel_applies(cfg: ModelConfig, batch: int, length: int) -> bool:
+    """Whether GQA's training / scoring forward (no cache, S > 1) of
+    ``batch`` rows of ``length`` runs the Pallas SPA kernel (DESIGN.md §3):
+    on a TPU, with no multi-device mesh installed (a ``pallas_call`` does
+    not partition under GSPMD), with a head dim that fills the kernel's
+    128-lane tiles, and with a visit list that fits its scalar memory.
+    Read from the platform and the shapes, never from an option."""
+    from repro.kernels.spa_attention import fits
+    mesh = current_mesh()
+    return (_platform() == "tpu" and (mesh is None or mesh.size == 1)
+            and cfg.head_dim % 128 == 0 and fits(batch, length, length))
+
+
+def attn_tile_share(cfg: ModelConfig, positions, segments):
+    """Share of attention tiles one training forward computes: the SPA
+    kernel's visit map over these positions and segments where GQA takes
+    the kernel, else 1 (the chunked scan computes every tile). A scalar
+    f32."""
+    if (cfg.use_mla or cfg.attention_free
+            or not (cfg.use_pallas_attention
+                    or spa_kernel_applies(cfg, *positions.shape))):
+        return jnp.float32(1.0)
+    from repro.kernels.ops import spa_tile_share
+    return spa_tile_share(positions, positions, segments, segments,
+                          window=cfg.sliding_window)
+
+
 def gqa_attention(params, cfg: ModelConfig, x, positions, segments, *,
                   cache: Optional[dict] = None, cache_offset=None,
                   page_table=None):
@@ -552,10 +583,11 @@ def gqa_attention(params, cfg: ModelConfig, x, positions, segments, *,
     q = constrain(q, "batch", None, "heads", None)
     kk = constrain(kk, "batch", None, None, None)
     vv = constrain(vv, "batch", None, None, None)
-    if cfg.use_pallas_attention and S > 1:
-        # production TPU path: fused block-sparse shared-prompt flash
-        # kernel — scores/probs never leave VMEM (§Perf iter A5), dead
-        # response x response tiles are skipped via the block map.
+    if S > 1 and ((cache is None and spa_kernel_applies(cfg, B, S))
+                  or cfg.use_pallas_attention):
+        # fused block-sparse shared-prompt flash kernel, differentiable:
+        # scores/probs never leave VMEM (§Perf iter A5) and dead
+        # response x response tiles are never visited (DESIGN.md §3).
         from repro.kernels.ops import spa_attention as _spa_kernel
         out = _spa_kernel(q, kk, vv, positions, kp, segments, ks,
                           window=cfg.sliding_window)

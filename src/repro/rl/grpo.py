@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RLConfig
 from repro.models import forward_hidden, token_logprobs
+from repro.models.attention import attn_tile_share
 from repro.optim.adam import adam_update
 
 
@@ -97,6 +98,7 @@ def grpo_loss(policy_params, cfg: ModelConfig, rl: RLConfig, mb: MicroBatch,
         "ratio_mean": (ratio * hard_mask).sum() / denom,
         "aux": aux,
         "n_tokens": hard_mask.sum(),
+        "attn_tile_share": attn_tile_share(cfg, mb.positions, mb.segments),
     }
     return loss, metrics
 

@@ -87,6 +87,94 @@ def test_spa_kernel_with_padding_tail():
                                atol=2e-5, rtol=2e-5)
 
 
+def _packed_rows(align, H, Hkv, D, dtype):
+    """One GRPO group packed by core/spa.py (3 responses of 40 after a
+    65-token prompt), with random q/k/v over its length."""
+    from repro.core.queue import RolloutGroup
+    from repro.core.spa import pack_spa
+    rng = np.random.RandomState(5)
+    g = RolloutGroup(
+        uid=0, prompt_ids=rng.randint(3, 250, size=(65,)).astype(np.int32),
+        response_ids=rng.randint(3, 250, size=(3, 40)).astype(np.int32),
+        response_len=np.asarray([40, 23, 31], np.int32),
+        rewards=np.asarray([1.0, 0.0, 1.0], np.float32), weight_version=0)
+    mb = pack_spa(g, np.zeros(3, np.float32), 65, 40, responses_per_row=3,
+                  align=align)
+    pos, seg = jnp.asarray(mb.positions), jnp.asarray(mb.segments)
+    S = pos.shape[1]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(6), 3)
+    return (jax.random.normal(kq, (1, S, H, D), dtype),
+            jax.random.normal(kk, (1, S, Hkv, D), dtype),
+            jax.random.normal(kv, (1, S, Hkv, D), dtype), pos, pos, seg, seg)
+
+
+def _layout_case(case, dtype):
+    """(q, k, v, q_pos, kv_pos, q_seg, kv_seg, window, bq, bk) per case."""
+    if case == "mha":
+        q, k, v, pos, seg = spa_layout(jax.random.PRNGKey(0), 1, 32, 16, 2,
+                                       2, 2, 64, dtype)
+        return q, k, v, pos, pos, seg, seg, None, 16, 16
+    if case == "gqa":
+        q, k, v, pos, seg = spa_layout(jax.random.PRNGKey(1), 2, 40, 24, 3,
+                                       4, 2, 64, dtype)
+        return q, k, v, pos, pos, seg, seg, None, 32, 32
+    if case == "window":
+        q, k, v, pos, seg = spa_layout(jax.random.PRNGKey(2), 1, 32, 16, 2,
+                                       4, 1, 32, dtype)
+        return q, k, v, pos, pos, seg, seg, 12, 16, 16
+    if case == "pad_tail":
+        q, k, v, pos, seg = spa_layout(jax.random.PRNGKey(3), 1, 17, 9, 2,
+                                       2, 1, 32, dtype, pad_tail=21)
+        return q, k, v, pos, pos, seg, seg, None, 16, 16
+    if case in ("align0", "align128"):
+        args = _packed_rows(0 if case == "align0" else 128, 4, 2, 32, dtype)
+        return args + ((None, 64, 64) if case == "align0"
+                       else (None, 128, 128))
+    # prompt_queries: the queries are the prompt alone, so no query tile
+    # has a live key tile past it and every response key tile is visited
+    # only to write its zero dK / dV
+    q, k, v, pos, seg = spa_layout(jax.random.PRNGKey(4), 1, 32, 16, 3,
+                                   2, 2, 32, dtype)
+    return (q[:, :32], k, v, pos[:, :32], pos, seg[:, :32], seg, None,
+            16, 16)
+
+
+@pytest.mark.parametrize(
+    "case,dtype",
+    [(c, jnp.float32) for c in ("mha", "gqa", "window", "pad_tail", "align0",
+                                "align128", "prompt_queries")]
+    + [(c, jnp.bfloat16) for c in ("mha", "gqa", "align128")])
+def test_spa_kernel_gradients(case, dtype):
+    """jax.grad through the kernel's custom VJP (the Pallas dQ and dK/dV
+    kernels) against autodiff of chunked_attention and of the dense f32
+    oracle, for dq, dk and dv."""
+    from repro.models.attention import chunked_attention
+    q, k, v, qp, kp, qs, ks, window, bq, bk = _layout_case(case, dtype)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape[:3] + v.shape[3:])
+
+    def grads(attn):
+        def loss(q, k, v):
+            o = attn(q, k, v, qp, kp, qs, ks, window=window)
+            return (o.astype(jnp.float32) * w).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda *a, **kw: spa_attention(*a, block_q=bq, block_k=bk,
+                                               **kw, **INTERP))
+    wants = (grads(spa_attention_ref),
+             grads(lambda *a, **kw: chunked_attention(*a, chunk_size=16,
+                                                      **kw)))
+    rel = 2e-5 if dtype == jnp.float32 else 3e-2
+    for want in wants:
+        for name, g, r in zip("qkv", got, want):
+            g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+            scale = np.abs(r).max()
+            assert np.abs(g - r).max() <= rel * scale, (name, case)
+    if case == "prompt_queries":
+        # response keys are seen by no query: their gradient is exactly 0
+        assert not np.asarray(got[1], np.float32)[:, 32:].any()
+        assert not np.asarray(got[2], np.float32)[:, 32:].any()
+
+
 def test_spa_equals_per_sample_attention():
     """The packed SPA output at response k's rows equals standard causal
     attention over [prompt; response_k] alone — the paper's exactness claim

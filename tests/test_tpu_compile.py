@@ -110,6 +110,63 @@ def test_spa_attention_compiles(one_chip):
              ((b, s), jnp.int32), ((b, s), jnp.int32), ((b, s), jnp.int32))
 
 
+# the GRPO cell's grad step: InternLM2-20B widths, one SPA-packed group of
+# a 512-token prompt and 8 x 384 responses in one 3,591-token row
+SPA_H, SPA_HKV, SPA_S = 48, 8, 511 + 8 * 385
+RESIDENT = 12_897_302_528       # the cell's peak bytes in use, beside which
+HBM = 16 * 2**30                # the grad step's temporaries must fit
+
+
+def _spa_parts():
+    from repro.kernels import spa_attention as spa
+    bq, bk = spa.BLOCK_Q, spa.BLOCK_K
+    nq, nk = -(-SPA_S // bq), -(-SPA_S // bk)
+    sq, sk = nq * bq, nk * bk
+    t = spa._Tiling(G=SPA_H // SPA_HKV, D=D, Dv=D, bq=bq, bk=bk, nq=nq,
+                    nk=nk, scale=D ** -0.5, window=None, interpret=False)
+    bf = lambda *s: (s, jnp.bfloat16)
+    i32 = lambda *s: (s, jnp.int32)
+    f32 = lambda *s: (s, jnp.float32)
+    qkv = (bf(1, sq, SPA_H * D), bf(1, sk, SPA_HKV * D), bf(1, sk, SPA_HKV * D))
+    pos = (i32(1, sq), i32(1, sk), i32(1, sq), i32(1, sk))
+    lists = (i32(nq * nk), i32())
+    stats = (bf(1, sq, SPA_H * D), f32(1, SPA_H, sq, 1), f32(1, SPA_H, sq, 1))
+    return spa, t, qkv, pos, lists, stats
+
+
+@pytest.mark.parametrize("kernel", ["forward", "dq", "dkv"])
+def test_spa_training_kernels_compile(one_chip, kernel):
+    """The forward with its log-sum-exp residual, and the dQ and dK/dV
+    kernels, at the cell's widths, over a dynamic live-tile grid."""
+    spa, t, qkv, pos, lists, stats = _spa_parts()
+    if kernel == "forward":
+        fn, shapes = partial(spa._forward, t), qkv + pos + lists
+    else:
+        fn = partial(spa._backward_dq if kernel == "dq"
+                     else spa._backward_dkv, t)
+        shapes = qkv + pos + stats + lists
+    _compile(fn, one_chip, *shapes)
+
+
+def test_spa_attention_grad_compiles_beside_the_resident_state(one_chip):
+    """jax.grad of the kernel at the cell's widths: all three Mosaic
+    kernels, and temporaries that fit beside the cell's resident bytes."""
+    def loss(q, k, v, qp, kp, qs, ks):
+        o = spa_attention(q, k, v, qp, kp, qs, ks, interpret=False)
+        return o.astype(jnp.float32).sum()
+
+    s = SPA_S
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                        ((1, s, SPA_H, D), jnp.bfloat16),
+                        ((1, s, SPA_HKV, D), jnp.bfloat16),
+                        ((1, s, SPA_HKV, D), jnp.bfloat16),
+                        *(((1, s), jnp.int32),) * 4)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes \
+        < HBM - RESIDENT
+
+
 def test_transfer_cast_compiles(one_chip):
     _compile(partial(transfer_cast, dtype=jnp.bfloat16, interpret=False),
              one_chip, ((3072, 8192), jnp.float32))
